@@ -307,6 +307,8 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--out", default=str(_REPO / "BENCH_graph.json"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     defaults = ((3, 24, 6) if args.quick else (6, 48, 12))
     requests = args.requests or defaults[0]

@@ -272,6 +272,8 @@ def main() -> None:
                     help="single exhaustive sweep: no cost-model pruning, "
                          "no predicted-vs-measured gate")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     report = bench_all(quick=args.quick, dtype=args.dtype,
                        cache_path=args.cache, iters=args.iters,

@@ -128,7 +128,7 @@ def bench(*, mesh_n, arch, requests, max_new, slots, page_size,
         "mesh_engine": {"kv_pool_bytes_per_device": kv_dev,
                         "weight_bytes_per_device": w_dev,
                         "tp_degree": sharded.tp_plan.degree,
-                        "kv_sharded": sharded.tp_plan.shard_kv,
+                        "kv_repeat": sharded.tp_plan.kv_repeat,
                         "decode_tps": round(m_n.decode_tps, 2)},
         "kv_bytes_reduction": round(kv_bytes_1 / max(kv_dev, 1), 3),
         "weight_bytes_reduction": round(w_bytes_1 / max(w_dev, 1), 3),
@@ -152,6 +152,8 @@ def main() -> None:
     ap.add_argument("--out", default=str(_REPO / "BENCH_parallel.json"))
     args = ap.parse_args()
     _ensure_devices(args.mesh)  # before any jax import
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     defaults = ((3, 6) if args.quick else (6, 12))
     report = bench(mesh_n=args.mesh, arch=args.arch,

@@ -184,6 +184,8 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--out", default=str(_REPO / "BENCH_quant.json"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     defaults = ((4, 24, 8) if args.quick else (8, 64, 16))
     requests = args.requests or defaults[0]

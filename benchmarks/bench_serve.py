@@ -178,6 +178,8 @@ def main() -> None:
     ap.add_argument("--prefill-chunk", type=int, default=16)
     ap.add_argument("--out", default=str(_REPO / "BENCH_serve.json"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     defaults = ((4, 24, 8) if args.quick else (8, 64, 16))
     requests = args.requests or defaults[0]

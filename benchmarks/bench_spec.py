@@ -168,6 +168,8 @@ def main() -> None:
                     help="skip the paired-draft-model configuration")
     ap.add_argument("--out", default=str(_REPO / "BENCH_spec.json"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     defaults = ((6, 16, 48, (2, 4)) if args.quick else (8, 32, 64, (2, 4, 8)))
     requests = args.requests or defaults[0]

@@ -5,7 +5,7 @@ lowers the jaxpr to the :mod:`repro.graph.ir` vocabulary, one IR node per
 primitive equation.  Two things make the result a *compiler* IR rather
 than a jaxpr mirror:
 
-* **call-like equations are inlined** — ``pjit``, ``custom_jvp_call`` /
+* **call-like equations are inlined** — ``jit``, ``custom_jvp_call`` /
   ``custom_vjp_call`` (every ``jax.nn`` activation is a custom_jvp
   function) and remat wrappers are flattened into their body equations, so
   a ``jax.nn.relu`` shows up as the fusable ``max(x, 0)`` it is instead of
@@ -33,21 +33,16 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 moved the public core types
-    from jax.extend import core as jcore  # type: ignore
-    _ = (jcore.Literal, jcore.DropVar, jcore.ClosedJaxpr)
-except Exception:  # pragma: no cover - 0.4.x image
-    from jax import core as jcore
+from jax.core import DropVar
+from jax.extend.core import ClosedJaxpr, Literal
 
 from .ir import Graph, Node, Value, canonical_op
 
 #: Call-like primitives that are pure wrappers around a body jaxpr: the
 #: tracer flattens them.  param key -> how to find the body.
-_INLINE_CALLS = ("pjit", "custom_jvp_call", "custom_vjp_call",
-                 "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
-                 "remat2", "checkpoint", "closed_call", "core_call",
-                 "xla_call")
-_BODY_PARAM_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
+_INLINE_CALLS = ("jit", "custom_jvp_call", "custom_vjp_call", "remat2",
+                 "closed_call")
+_BODY_PARAM_KEYS = ("jaxpr", "call_jaxpr")
 
 #: Longest ``lax.scan`` the tracer unrolls into the graph.  A deep layer
 #: scan past this produces a graph too large to fuse profitably (and to
@@ -98,7 +93,7 @@ def _closed_body(eqn) -> Any:
             continue
         if hasattr(body, "jaxpr"):          # already a ClosedJaxpr
             return body
-        return jcore.ClosedJaxpr(body, ())  # open Jaxpr (remat2)
+        return ClosedJaxpr(body, ())  # open Jaxpr (remat2)
     return None
 
 
@@ -141,7 +136,7 @@ def _bind_consts(g: Graph, env, constvars, consts) -> None:
 
 
 def _read(g: Graph, env, var) -> int:
-    if isinstance(var, jcore.Literal):
+    if isinstance(var, Literal):
         v = g.new_value(var.aval.shape, var.aval.dtype, kind="const",
                         array=jnp.asarray(var.val, var.aval.dtype))
         return v.id
@@ -164,7 +159,7 @@ def _lower_eqns(g: Graph, env, eqns) -> None:
             _bind_consts(g, sub_env, body.jaxpr.constvars, body.consts)
             _lower_eqns(g, sub_env, body.jaxpr.eqns)
             for call_out, body_out in zip(eqn.outvars, body.jaxpr.outvars):
-                if not isinstance(call_out, jcore.DropVar):
+                if not isinstance(call_out, DropVar):
                     env[call_out] = _read(g, sub_env, body_out)
             continue
 
@@ -173,7 +168,7 @@ def _lower_eqns(g: Graph, env, eqns) -> None:
         for ovar in eqn.outvars:
             v = g.new_value(ovar.aval.shape, ovar.aval.dtype)
             out_ids.append(v.id)
-            if not isinstance(ovar, jcore.DropVar):
+            if not isinstance(ovar, DropVar):
                 env[ovar] = v.id
         g.nodes.append(Node(
             id=g.next_node_id(),

@@ -7,13 +7,15 @@ Per head (head dim P, state dim N) with scalar per-head decay:
 
 ``h`` is a decaying accumulator of rank-1 updates — the APR pattern again.
 The kernel keeps h in VMEM scratch across time-chunk grid steps; only the
-x/B/C/dt chunk streams and y chunks touch HBM.
+x/B/C/dt chunk streams and y chunks touch HBM.  Time steps run in 8-step
+slabs (one aligned load per stream), unrolled within a slab.
 
 Grid: (B, H, T/chunk).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -21,39 +23,54 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: Rows per aligned load: Mosaic loads VMEM in (8, 128) tiles, so a time
+#: step is read as one row of an 8-row slab rather than a one-row slice.
+_SLAB = 8
+
+
 def _mamba2_kernel(
     x_ref,    # (chunk, P)
     b_ref,    # (chunk, N)
     c_ref,    # (chunk, N)
     dt_ref,   # (chunk, 1)
-    a_ref,    # (1, 1)  per-head log-decay (negative)
-    d_ref,    # (1, 1)  per-head skip
+    a_ref,    # SMEM (H,)  per-head log-decay (negative)
+    d_ref,    # SMEM (H,)  per-head skip
     o_ref,    # (chunk, P)
-    h_ref,    # VMEM (P, N)  APR: SSM state
+    h_ref,    # VMEM (N, P)  APR: SSM state, stored transposed
     *,
     chunk: int,
 ):
+    head = pl.program_id(1)
     c_idx = pl.program_id(2)
 
     @pl.when(c_idx == 0)
     def _reset():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    a = a_ref[0, 0].astype(jnp.float32)
-    d_skip = d_ref[0, 0].astype(jnp.float32)
+    a = a_ref[head]
+    d_skip = d_ref[head]
+    g = math.gcd(chunk, _SLAB)
 
-    def step(t, h):
-        x = x_ref[t, :].astype(jnp.float32)        # (P,)
-        bt = b_ref[t, :].astype(jnp.float32)       # (N,)
-        ct = c_ref[t, :].astype(jnp.float32)       # (N,)
-        dt = dt_ref[t, 0].astype(jnp.float32)
-        decay = jnp.exp(a * dt)
-        h = decay * h + dt * (x[:, None] * bt[None, :])   # (P, N)
-        y = h @ ct + d_skip * x
-        o_ref[t, :] = y.astype(o_ref.dtype)
+    def slab(i, h):
+        rows = pl.ds(pl.multiple_of(i * g, g), g)
+        x = x_ref[rows, :].astype(jnp.float32)         # (g, P)
+        b_t = b_ref[rows, :].astype(jnp.float32).T     # (N, g)
+        c_t = c_ref[rows, :].astype(jnp.float32).T     # (N, g)
+        # (g, 1) -> (g, P): Mosaic broadcasts one axis at a time, so widen
+        # dt along lanes once per slab instead of per (1, 1) element
+        dt = jnp.broadcast_to(dt_ref[rows, :].astype(jnp.float32), x.shape)
+        row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        ys = jnp.zeros(x.shape, jnp.float32)
+        for j in range(g):
+            xj, dtj = x[j:j + 1, :], dt[j:j + 1, :]    # (1, P) each
+            h = jnp.exp(a * dtj) * h + b_t[:, j:j + 1] * (dtj * xj)
+            y = jnp.sum(h * c_t[:, j:j + 1], axis=0, keepdims=True) \
+                + d_skip * xj                          # (1, P)
+            ys = jnp.where(row == j, y, ys)
+        o_ref[rows, :] = ys.astype(o_ref.dtype)
         return h
 
-    h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
+    h_ref[...] = jax.lax.fori_loop(0, chunk // g, slab, h_ref[...])
 
 
 def mamba2_call(
@@ -85,12 +102,12 @@ def mamba2_call(
             pl.BlockSpec((None, None, chunk, n), lambda i, j, cc: (i, j, cc, 0)),
             pl.BlockSpec((None, None, chunk, n), lambda i, j, cc: (i, j, cc, 0)),
             pl.BlockSpec((None, None, chunk, 1), lambda i, j, cc: (i, j, cc, 0)),
-            pl.BlockSpec((None, 1, 1), lambda i, j, cc: (j, 0, 0)),
-            pl.BlockSpec((None, 1, 1), lambda i, j, cc: (j, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((None, None, chunk, p), lambda i, j, cc: (i, j, cc, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, h, t, p), x.dtype),
-        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xh, bh, ch, dth, a.reshape(h, 1, 1), d.reshape(h, 1, 1))
+    )(xh, bh, ch, dth, a.astype(jnp.float32), d.astype(jnp.float32))
     return out.transpose(0, 2, 1, 3)

@@ -13,12 +13,13 @@ scratch across time-chunk grid steps; HBM sees only r/k/v/w chunk streams in
 and y chunks out, never the O(D^2) state.
 
 Grid: (B, H, T/chunk); the chunk loop inside the kernel is a fori_loop over
-time steps (the sequential dependency is fundamental, the state residency
-is what the APR buys).
+8-step slabs, unrolled within a slab (the sequential dependency is
+fundamental, the state residency is what the APR buys).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -26,12 +27,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+#: Rows per aligned load: Mosaic loads VMEM in (8, 128) tiles, so a time
+#: step is read as one row of an 8-row slab rather than a one-row slice.
+_SLAB = 8
+
+
 def _rwkv6_kernel(
     r_ref,   # (chunk, D)
     k_ref,   # (chunk, D)
     v_ref,   # (chunk, D)
     w_ref,   # (chunk, D)  decay in (0,1)
-    u_ref,   # (1, D)      bonus
+    u_ref,   # (D, 1)      bonus, as a column
     o_ref,   # (chunk, D)
     s_ref,   # VMEM (D, D) APR: recurrent state
     *,
@@ -43,19 +49,28 @@ def _rwkv6_kernel(
     def _reset():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    u = u_ref[0, :].astype(jnp.float32)
+    u = u_ref[...].astype(jnp.float32)                 # (D, 1)
+    g = math.gcd(chunk, _SLAB)
 
-    def step(t, state):
-        r = r_ref[t, :].astype(jnp.float32)
-        k = k_ref[t, :].astype(jnp.float32)
-        v = v_ref[t, :].astype(jnp.float32)
-        w = w_ref[t, :].astype(jnp.float32)
-        kv = k[:, None] * v[None, :]              # (D, D) rank-1 update
-        y = ((state + u[:, None] * kv).T @ r)     # (D,)
-        o_ref[t, :] = y.astype(o_ref.dtype)
-        return w[:, None] * state + kv            # decay + accumulate
+    def slab(i, state):
+        rows = pl.ds(pl.multiple_of(i * g, g), g)
+        v = v_ref[rows, :].astype(jnp.float32)         # (g, D)
+        # columns of r/k/w index the state's rows
+        r_t = r_ref[rows, :].astype(jnp.float32).T     # (D, g)
+        k_t = k_ref[rows, :].astype(jnp.float32).T
+        w_t = w_ref[rows, :].astype(jnp.float32).T
+        row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        ys = jnp.zeros(v.shape, jnp.float32)
+        for j in range(g):
+            kv = k_t[:, j:j + 1] * v[j:j + 1, :]       # (D, D) rank-1 update
+            y = jnp.sum((state + u * kv) * r_t[:, j:j + 1], axis=0,
+                        keepdims=True)                 # (1, D)
+            ys = jnp.where(row == j, y, ys)
+            state = w_t[:, j:j + 1] * state + kv       # decay + accumulate
+        o_ref[rows, :] = ys.astype(o_ref.dtype)
+        return state
 
-    s_ref[...] = jax.lax.fori_loop(0, chunk, step, s_ref[...])
+    s_ref[...] = jax.lax.fori_loop(0, chunk // g, slab, s_ref[...])
 
 
 def rwkv6_call(
@@ -83,11 +98,11 @@ def rwkv6_call(
             pl.BlockSpec((None, None, chunk, d), lambda i, j, c: (i, j, c, 0)),
             pl.BlockSpec((None, None, chunk, d), lambda i, j, c: (i, j, c, 0)),
             pl.BlockSpec((None, None, chunk, d), lambda i, j, c: (i, j, c, 0)),
-            pl.BlockSpec((None, 1, d), lambda i, j, c: (j, 0, 0)),
+            pl.BlockSpec((None, d, 1), lambda i, j, c: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, None, chunk, d), lambda i, j, c: (i, j, c, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, t, d), r.dtype),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=interpret,
-    )(bhtd(r), bhtd(k), bhtd(v), bhtd(w), u.reshape(h, 1, d))
+    )(bhtd(r), bhtd(k), bhtd(v), bhtd(w), u.reshape(h, d, 1))
     return out.transpose(0, 2, 1, 3)  # back to (B, T, H, D)

@@ -1,6 +1,12 @@
 """Serving launcher: asyncio streaming server over paged continuous batching.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --requests 6
+    PYTHONPATH=src python -m repro.launch.serve --arch chatglm3-6b --requests 6
+
+The model is built at its published widths with random weights from
+``--seed``; ``--smoke`` serves the reduced same-family config instead (the
+size the CPU tests and examples use).  ``chip_smoke.py`` at the repo root
+drives the same functions (:func:`load_model`, :func:`build_engine`,
+:func:`serve_prompts`) on the chip.
 
 Requests are submitted through :class:`repro.serve.AsyncServeFrontend` and
 stream their tokens back concurrently — the engine admits late arrivals
@@ -44,11 +50,17 @@ engine over the first N devices; greedy outputs stay token-identical to
 import argparse
 import asyncio
 import os
+from typing import List, Optional, Sequence, Tuple
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3-8b")
+    ap.add_argument("--arch", default="chatglm3-6b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced same-family config instead of "
+                         "the published widths")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the random weights")
     ap.add_argument("--engine", choices=("paged", "slot"), default="paged")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--slots", type=int, default=4)
@@ -94,31 +106,24 @@ def main() -> None:
                     help="tensor-parallel degree: shard the paged engine "
                          "over the first N devices (repro.parallel.tp; "
                          "simulated on CPU via host-platform devices)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.mesh > 1 and "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        # must be decided before jax initialises its backends; real
-        # accelerators already expose their device count, but a plain CPU
-        # process defaults to one device
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.mesh}").strip()
 
+def load_model(args: argparse.Namespace):
+    """``(cfg, bundle, params)`` for ``args.arch``: published widths, or
+    the smoke config under ``--smoke``; random weights from ``--seed``,
+    int8 under ``--int8-weights``."""
     import jax
 
-    from ..configs import get_config, get_draft_config
+    from ..configs import get_config
     from ..models import build_model
-    from ..parallel.sharding import ParallelContext
-    from ..serve import (SLO_THROUGHPUT, SLO_TTFT, AsyncServeFrontend,
-                         PagedServeEngine, ServeEngine)
 
-    cfg = get_config(args.arch, smoke=True)
-    if args.mesh > 1 and (cfg.num_heads % args.mesh
-                          or cfg.num_kv_heads % args.mesh
-                          or cfg.d_ff % args.mesh):
+    cfg = get_config(args.arch, smoke=args.smoke)
+    if args.smoke and args.mesh > 1 and (cfg.num_heads % args.mesh
+                                         or cfg.num_kv_heads % args.mesh
+                                         or cfg.d_ff % args.mesh):
         # Lift the reduced smoke geometry to a TP-divisible head layout
-        # (full-size configs divide naturally; the smoke ones are tiny).
+        # (published configs divide naturally; the smoke ones are tiny).
         import dataclasses
         up = lambda v, n: -(-v // n) * n
         hkv = up(cfg.num_kv_heads, args.mesh)
@@ -129,9 +134,20 @@ def main() -> None:
         print(f"note: smoke geometry lifted for --mesh {args.mesh}: "
               f"heads={h} kv_heads={hkv} d_ff={cfg.d_ff}")
     bundle = build_model(cfg)
-    params = bundle.init_params(jax.random.PRNGKey(0))
+    params = bundle.init_params(jax.random.PRNGKey(args.seed))
     if args.int8_weights:
         params = bundle.quantize_params(params)
+    return cfg, bundle, params
+
+
+def build_engine(args: argparse.Namespace, cfg, bundle, params):
+    """The serving engine the flags describe, over ``params``."""
+    import jax
+
+    from ..configs import get_draft_config
+    from ..parallel.sharding import ParallelContext
+    from ..serve import PagedServeEngine, ServeEngine
+
     if args.mesh > 1:
         if not (args.engine == "paged" and bundle.supports_paged_kv):
             raise SystemExit(
@@ -179,10 +195,10 @@ def main() -> None:
             if args.draft_model == "ngram":
                 draft_cfg = None
             elif args.draft_model == "auto":
-                draft_cfg = get_draft_config(args.arch, smoke=True)
+                draft_cfg = get_draft_config(args.arch, smoke=args.smoke)
             else:  # an explicit *draft* arch name (not a target arch)
-                draft_cfg = get_draft_config(args.draft_model, smoke=True,
-                                             pairing=False)
+                draft_cfg = get_draft_config(args.draft_model,
+                                             smoke=args.smoke, pairing=False)
                 if draft_cfg is None:
                     raise SystemExit(
                         f"no draft config registered as {args.draft_model!r}")
@@ -193,7 +209,8 @@ def main() -> None:
             else:
                 print(f"speculative: draft={draft_cfg.name}, k={args.spec_k}")
                 draft_bundle = build_draft_model(cfg, draft_cfg)
-                draft_params = draft_bundle.init_params(jax.random.PRNGKey(1))
+                draft_params = draft_bundle.init_params(
+                    jax.random.PRNGKey(args.seed + 1))
                 engine = SpeculativeServeEngine(
                     bundle, params, pctx, spec_k=args.spec_k,
                     draft_bundle=draft_bundle, draft_params=draft_params,
@@ -211,34 +228,39 @@ def main() -> None:
             print("note: --prefix-sharing only applies to the paged engine")
         engine = ServeEngine(bundle, params, pctx, slots=args.slots,
                              max_seq=max(128, args.prompt_len + args.max_new + 2))
+    return engine
 
-    if args.explain:
-        report = (engine.graph_schedule_report()
-                  if isinstance(engine, PagedServeEngine) else "")
-        print(report if report else
-              "no graph schedules to explain (needs --graph-prefill with "
-              "the cost model on)")
 
-    # a shared prompt head (the "system prompt") + a per-request tail, so
-    # --prefix-sharing has something to dedupe
-    head_len = max(args.prompt_len // 2, 1)
+def launcher_prompts(n: int, prompt_len: int) -> List[List[int]]:
+    """A shared prompt head (the "system prompt") + a per-request tail, so
+    --prefix-sharing has something to dedupe."""
+    head_len = max(prompt_len // 2, 1)
     head = list(range(2, 2 + head_len))
+    return [head + [100 + i] * (prompt_len - head_len) for i in range(n)]
 
-    async def serve() -> list:
-        rows = []
+
+def serve_prompts(engine, prompts: Sequence[List[int]], max_new: int, *,
+                  slo_mix: bool = False) -> Tuple[List[List[int]], list]:
+    """Submit every prompt through :class:`AsyncServeFrontend` at once and
+    drain the streams; returns (tokens per request, per-request metrics).
+    ``slo_mix`` marks every other request TTFT-class."""
+    from ..serve import SLO_THROUGHPUT, SLO_TTFT, AsyncServeFrontend
+
+    async def serve():
         async with AsyncServeFrontend(engine) as front:
             streams = []
-            for i in range(args.requests):
-                slo = (SLO_TTFT if args.slo_mix and i % 2 else
-                       SLO_THROUGHPUT)
-                tail = [100 + i] * (args.prompt_len - head_len)
+            for i, prompt in enumerate(prompts):
+                slo = SLO_TTFT if slo_mix and i % 2 else SLO_THROUGHPUT
                 streams.append(await front.submit(
-                    head + tail, max_new_tokens=args.max_new, slo=slo))
-            await asyncio.gather(*(s.drain() for s in streams))
-            rows = [s.metrics() for s in streams]
-        return rows
+                    prompt, max_new_tokens=max_new, slo=slo))
+            outs = await asyncio.gather(*(s.drain() for s in streams))
+            return list(outs), [s.metrics() for s in streams]
 
-    rows = asyncio.run(serve())
+    return asyncio.run(serve())
+
+
+def print_report(args: argparse.Namespace, engine, rows: list) -> None:
+    from ..serve import PagedServeEngine
 
     done = sum(1 for row in rows if row["tokens"] > 0)
     print(f"served {done}/{args.requests} requests")
@@ -274,6 +296,34 @@ def main() -> None:
                   f"kv pool/device={engine.kv_pool_bytes_per_device()}B "
                   f"(logical {engine.kv_pool_bytes()}B)  "
                   f"weights/device={engine.weight_bytes_per_device()}B")
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    if args.mesh > 1 and "xla_force_host_platform_device_count" not in \
+            os.environ.get("XLA_FLAGS", ""):
+        # must be decided before jax initialises its backends; real
+        # accelerators already expose their device count, but a plain CPU
+        # process defaults to one device
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.mesh}").strip()
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
+
+    cfg, bundle, params = load_model(args)
+    engine = build_engine(args, cfg, bundle, params)
+    if args.explain:
+        from ..serve import PagedServeEngine
+        report = (engine.graph_schedule_report()
+                  if isinstance(engine, PagedServeEngine) else "")
+        print(report if report else
+              "no graph schedules to explain (needs --graph-prefill with "
+              "the cost model on)")
+    _, rows = serve_prompts(engine,
+                            launcher_prompts(args.requests, args.prompt_len),
+                            args.max_new, slo_mix=args.slo_mix)
+    print_report(args, engine, rows)
 
 
 if __name__ == "__main__":

@@ -26,7 +26,6 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from ..parallel import compat
 from . import layers
 from .layers import ParamBuilder, Params
 
@@ -110,7 +109,7 @@ def moe_ffn_local(p: Params, prefix: str, cfg: ModelConfig, x: jax.Array,
     E_local = E/ep on axis 0 and tokens are exchanged with two all-to-alls.
     """
     t = x.shape[0]
-    ep = compat.axis_size(ep_axis) if ep_axis else 1
+    ep = jax.lax.axis_size(ep_axis) if ep_axis else 1
     cap = _capacity(t, cfg, ep)
     dispatched, meta = _route_and_dispatch(x, p[f"{prefix}.router"], cfg, cap)
 
@@ -171,7 +170,7 @@ def moe_block(p: Params, prefix: str, cfg: ModelConfig, x: jax.Array,
             with layers.manual_tp(ff_ax, tp_deg):
                 return moe_ffn_local(sp, prefix, cfg, xl, ep_axis=ep_axis)
 
-        out = compat.shard_map(
+        out = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=({k: spec_for(k) for k in sub}, P(tuple(pctx.dp_axes))),

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 
 BLOCK = 2048
 
@@ -57,7 +56,7 @@ def _compressed_pod_mean(g: jax.Array, pod_axis: str) -> jax.Array:
     smax = jax.lax.pmax(scale, pod_axis)
     qr = jnp.clip(jnp.round(q.astype(jnp.float32) * (scale / smax)), -127, 127)
     qsum = jax.lax.psum(qr.astype(jnp.int32), pod_axis)
-    n = compat.axis_size(pod_axis)
+    n = jax.lax.axis_size(pod_axis)
     flat = qsum.astype(jnp.float32) * smax / n
     total = 1
     for s in g.shape:
@@ -99,7 +98,7 @@ def compressed_value_and_grad(
     rep = lambda tree: jax.tree.map(lambda _: P(), tree,
                                     is_leaf=lambda x: hasattr(x, "shape"))
     batch_specs = {k: P(batch_axes) for k in batch}
-    return compat.shard_map(
+    return jax.shard_map(
         podwise, mesh=mesh,
         in_specs=(rep(params), batch_specs),
         out_specs=(P(), rep(params)),

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import compat
 
 
 def pipeline_apply(
@@ -73,7 +72,7 @@ def pipeline_apply(
                      is_leaf=lambda x: hasattr(x, "shape")),
         P(),
     )
-    return compat.shard_map(
+    return jax.shard_map(
         staged, mesh=mesh, in_specs=in_specs, out_specs=P(),
         axis_names={stage_axis}, check_vma=False,
     )(stage_params, microbatches)

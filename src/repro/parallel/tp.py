@@ -8,8 +8,7 @@ prefix cache, COW splits, and defrag in ``repro.serve.paged_cache`` stay
 single-source: one block table drives N per-shard page pools in lockstep.
 
 The decode/prefill step is a **fully-manual** ``shard_map`` region (manual
-over every mesh axis — the only kind the image's jax 0.4.x compiles; see
-``docs/known_failures.md``) wrapping the unmodified
+over every mesh axis) wrapping the unmodified
 :func:`repro.models.lm.lm_decode_paged` with a *local* ModelConfig whose
 head/ff/kv-head counts are divided by the TP degree.  The two collectives
 are explicit:
@@ -29,10 +28,11 @@ Sharding rules (``plan_tp``):
 
 * ``num_heads`` and ``d_ff`` must divide the TP degree (hard requirement:
   their tp_einsum contractions are unconditionally psummed);
-* KV heads shard when divisible, else **replicate** (the GQA fallback
-  production TP uses — each shard then computes the full K/V projection
-  and writes identical values to its full-size pool), requiring the local
-  head count to still cover the GQA group structure;
+* KV heads shard when divisible.  With fewer KV heads than shards
+  (chatglm3-6b: 2 over 4) each KV head is **repeated** ``N / hkv`` times
+  in the K/V projections and the page pools before sharding, so every
+  shard holds the one KV head its query heads attend to (a shard's query
+  heads always fall in one GQA group then);
 * an untied lm_head vocab-shards when ``padded_vocab`` divides, else
   replicates; the embedding table always replicates (token gather stays
   local, and a tied head then emits full-width logits with no gather).
@@ -49,10 +49,10 @@ import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat
 from .sharding import ParallelContext
 
 
@@ -78,7 +78,7 @@ class TPPlan:
     """How one model shards over a TP-only mesh (see :func:`plan_tp`)."""
     degree: int
     local_cfg: Any               # ModelConfig with divided head/ff counts
-    shard_kv: bool               # KV heads (and page pools) sharded?
+    kv_repeat: int               # copies of each KV head before sharding
     shard_vocab: bool            # untied lm_head vocab-sharded?
 
 
@@ -90,7 +90,7 @@ def plan_tp(cfg, degree: int) -> TPPlan:
     the divided head count cannot change the derived per-head dim.
     """
     if degree <= 1:
-        return TPPlan(1, cfg, False, False)
+        return TPPlan(1, cfg, 1, False)
     h, hkv, ff = cfg.num_heads, cfg.num_kv_heads, cfg.d_ff
     if h % degree:
         raise ValueError(
@@ -104,8 +104,15 @@ def plan_tp(cfg, degree: int) -> TPPlan:
         raise ValueError(
             f"tensor parallelism needs dense_residual_ff % mesh == 0 "
             f"(got {cfg.dense_residual_ff} over {degree} shards)")
-    shard_kv = hkv % degree == 0
-    local_hkv = hkv // degree if shard_kv else hkv
+    if hkv % degree == 0:
+        kv_repeat = 1
+    elif degree % hkv == 0:
+        kv_repeat = degree // hkv
+    else:
+        raise ValueError(
+            f"tensor parallelism needs num_kv_heads and the mesh to divide "
+            f"one another (got {hkv} KV heads over {degree} shards)")
+    local_hkv = hkv * kv_repeat // degree
     if (h // degree) % local_hkv:
         raise ValueError(
             f"GQA layout unshardable: {h} query heads / {hkv} KV heads "
@@ -122,7 +129,36 @@ def plan_tp(cfg, degree: int) -> TPPlan:
         name=f"{cfg.name}-tp{degree}",
     )
     shard_vocab = (not cfg.tie_embeddings) and cfg.padded_vocab % degree == 0
-    return TPPlan(degree, local_cfg, shard_kv, shard_vocab)
+    return TPPlan(degree, local_cfg, kv_repeat, shard_vocab)
+
+
+def repeat_kv_heads(params: Dict[str, Any], cache: Any,
+                    logical: Dict[str, Tuple], plan: TPPlan):
+    """``(params, cache)`` with every KV head repeated ``plan.kv_repeat``
+    times, adjacent copies together, so sharding the KV-head axis hands
+    shard ``s`` a copy of KV head ``s // kv_repeat`` — the one its query
+    heads ``[s*h/N, (s+1)*h/N)`` attend to.  Identity when kv_repeat is 1.
+    The K/V projections repeat along their (kv_heads x head_dim) output
+    axis (int8 payload and scale alike), the page pools along axis 4."""
+    r = plan.kv_repeat
+    if r == 1:
+        return params, cache
+    hkv = plan.degree * plan.local_cfg.num_kv_heads // r
+
+    def repeat(a, axis):
+        a = jnp.asarray(a)
+        split = a.shape[:axis] + (hkv, -1) + a.shape[axis + 1:]
+        out = jnp.repeat(a.reshape(split), r, axis=axis)
+        return out.reshape(a.shape[:axis] + (-1,) + a.shape[axis + 1:])
+
+    params = dict(params)
+    for name, log in logical.items():
+        if "kv_heads" in log:
+            axis = log.index("kv_heads")
+            params[name] = jax.tree.map(lambda a: repeat(a, axis),
+                                        params[name])
+    cache = jax.tree.map(lambda a: repeat(a, _KV_HEAD_AXIS), cache)
+    return params, cache
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +167,7 @@ def plan_tp(cfg, degree: int) -> TPPlan:
 
 #: logical param axes that shard over the TP axis unconditionally (their
 #: tp_einsum contractions are always psummed inside the manual region)
-_ALWAYS_TP = ("heads", "ff", "ssm_inner")
+_ALWAYS_TP = ("heads", "kv_heads", "ff", "ssm_inner")
 
 
 def tp_param_specs(params: Dict[str, Any], logical: Dict[str, Tuple],
@@ -151,8 +187,6 @@ def tp_param_specs(params: Dict[str, Any], logical: Dict[str, Tuple],
         for ax in log:
             if ax in _ALWAYS_TP:
                 base.append(axis)
-            elif ax == "kv_heads":
-                base.append(axis if plan.shard_kv else None)
             elif ax == "vocab":
                 # the embed table replicates (local token gather; tied head
                 # emits full logits); only an untied lm_head vocab-shards
@@ -185,14 +219,11 @@ def tp_param_specs(params: Dict[str, Any], logical: Dict[str, Tuple],
 _KV_HEAD_AXIS = 4
 
 
-def tp_cache_specs(cache: Dict[str, Any], plan: TPPlan,
+def tp_cache_specs(cache: Dict[str, Any],
                    axis: str = "model") -> Dict[str, Any]:
     """PartitionSpecs for the KV page pools: sharded over the kv-head axis
-    when the plan shards KV heads, else replicated (each shard keeps a full
-    pool and writes identical values — the GQA-replication fallback)."""
+    (after :func:`repeat_kv_heads` where the plan repeats KV heads)."""
     def spec(a):
-        if not plan.shard_kv:
-            return P()
         dims = [None] * a.ndim
         dims[_KV_HEAD_AXIS] = axis
         return P(*dims)
@@ -201,8 +232,7 @@ def tp_cache_specs(cache: Dict[str, Any], plan: TPPlan,
 
 def shard_tree(tree: Any, mesh: Mesh, specs: Any) -> Any:
     """device_put every leaf with its NamedSharding (specs is a matching
-    tree of PartitionSpecs; P flattens like a tuple on legacy jax, so the
-    trees are zipped leaf-wise, not tree.mapped)."""
+    tree of PartitionSpecs, zipped leaf-wise with is_leaf=P)."""
     leaves, treedef = jax.tree.flatten(tree)
     spec_leaves, _ = jax.tree.flatten(
         specs, is_leaf=lambda x: isinstance(x, P))
@@ -257,7 +287,7 @@ def make_tp_decode_paged(bundle, pctx: ParallelContext, plan: TPPlan,
                                       tokens, lengths, new_counts,
                                       block_tables)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=pctx.mesh,
         in_specs=(param_specs, cache_specs, P(), P(), P(), P()),
         out_specs=(P(), cache_specs),

@@ -15,8 +15,10 @@ Profiles:
   not a hardware target); only *relative* ordering of candidates is
   meaningful, which is all the autotune pruner needs off-TPU.
 
-Selection: :func:`get_profile` resolves an explicit name, else the
-``REPRO_HW_PROFILE`` environment variable, else ``tpu_v5e``.  New targets
+Selection: :func:`get_profile` resolves an explicit name, else on a TPU
+backend the attached chip's ``device_kind`` (:data:`TPU_KINDS`; an unknown
+kind raises), else the ``REPRO_HW_PROFILE`` environment variable, else
+``tpu_v5e``.  New targets
 register with :func:`register_profile`; ``tools/check_docs.py`` requires
 every registered profile name to be documented in ``docs/cost_model.md``.
 
@@ -67,15 +69,33 @@ def register_profile(profile: HardwareProfile) -> HardwareProfile:
     return profile
 
 
+#: ``device_kind`` as JAX reports it -> profile of that chip
+TPU_KINDS: Dict[str, str] = {"TPU v5 lite": "tpu_v5e"}
+
+
 def get_profile(name: Optional[str] = None) -> HardwareProfile:
-    """Resolve a profile: explicit ``name`` > ``$REPRO_HW_PROFILE`` >
-    ``tpu_v5e``."""
-    resolved = name or os.environ.get(_ENV_VAR) or DEFAULT_PROFILE
+    """Resolve a profile: explicit ``name``; else, on a TPU backend, the
+    profile of the attached chip's ``device_kind`` (an unknown kind is an
+    error, never a default); else ``$REPRO_HW_PROFILE`` > ``tpu_v5e``."""
+    resolved = name or _tpu_profile_name() or os.environ.get(_ENV_VAR) \
+        or DEFAULT_PROFILE
     try:
         return _PROFILES[resolved]
     except KeyError:
         raise KeyError(f"no hardware profile {resolved!r}; "
                        f"known: {sorted(_PROFILES)}") from None
+
+
+def _tpu_profile_name() -> Optional[str]:
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    kind = jax.devices()[0].device_kind
+    if kind not in TPU_KINDS:
+        raise KeyError(f"no hardware profile for TPU device kind {kind!r}; "
+                       f"known kinds: {sorted(TPU_KINDS)}")
+    return TPU_KINDS[kind]
 
 
 def all_profiles() -> Dict[str, HardwareProfile]:

@@ -351,10 +351,11 @@ class PagedServeEngine:
             # vocab over the TP axis), KV pools over their kv-head axis.
             # One global cache keeps the page/block-table indexing shared;
             # each device physically holds only its heads' slice.
+            params, self.cache = self._tp.repeat_kv_heads(
+                params, self.cache, bundle.logical_axes(), self.tp_plan)
             pspecs = self._tp.tp_param_specs(params, bundle.logical_axes(),
                                              self.tp_plan, pctx.tp_axis)
-            cspecs = self._tp.tp_cache_specs(self.cache, self.tp_plan,
-                                             pctx.tp_axis)
+            cspecs = self._tp.tp_cache_specs(self.cache, pctx.tp_axis)
             self.params = self._tp.shard_tree(params, mesh, pspecs)
             self.cache = self._tp.shard_tree(self.cache, mesh, cspecs)
             self._decode = jax.jit(self._tp.make_tp_decode_paged(

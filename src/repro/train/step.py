@@ -131,7 +131,7 @@ def make_train_step(
 
 
 # ---------------------------------------------------------------------------
-# Sharding assembly for pjit (params / optimizer / batch / cache specs).
+# Sharding assembly for jit (params / optimizer / batch / cache specs).
 # ---------------------------------------------------------------------------
 
 

@@ -16,7 +16,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs import get_config
 from repro.configs.shapes import ShapeSpec
-from repro.parallel.compat import set_mesh
 from repro.checkpoint import CheckpointManager
 from repro.models import build_model
 from repro.models.moe import moe_block
@@ -41,7 +40,7 @@ def check_moe_ep_matches_local():
                           jnp.float32).astype(jnp.bfloat16)
 
     local = moe_block(moe_p, "moe", cfg, x, None)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, P(("data",), None, None)))
         dist = jax.jit(lambda p, v: moe_block(p, "moe", cfg, v, pctx))(moe_p, xs)
     err = float(jnp.max(jnp.abs(local.astype(jnp.float32) - dist.astype(jnp.float32))))
@@ -63,7 +62,8 @@ def check_pipeline_parallel():
         return jnp.tanh(x @ w)
 
     xs = jax.random.normal(jax.random.PRNGKey(1), (M, mb, d), jnp.float32)
-    out_pp = pipeline_apply(layer, ws, xs, mesh)
+    with jax.set_mesh(mesh):
+        out_pp = pipeline_apply(layer, ws, xs, mesh)
     ref = xs
     for i in range(L):
         ref = jax.vmap(lambda x: layer(ws[i], x))(ref)
@@ -80,7 +80,8 @@ def check_pipeline_parallel():
             r = layer(ws[i], r)
         return jnp.sum(r ** 2)
 
-    g_pp = jax.grad(loss_pp)(ws)
+    with jax.set_mesh(mesh):
+        g_pp = jax.grad(loss_pp)(ws)
     g_ref = jax.grad(loss_ref)(ws)
     np.testing.assert_allclose(np.asarray(g_pp), np.asarray(g_ref),
                                rtol=1e-4, atol=1e-4)
@@ -107,7 +108,7 @@ def check_compression():
     from repro.train.step import _loss_fn
     import functools
     loss_fn = functools.partial(_loss_fn, bundle, pctx)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l_exact, g_exact = jax.jit(jax.value_and_grad(loss_fn))(params, batch)
         l_c, g_c = jax.jit(lambda p, b: compressed_value_and_grad(
             loss_fn, p, b, pctx, enabled=True))(params, batch)
@@ -173,11 +174,46 @@ def check_elastic_remesh():
     print("CHECK elastic_remesh OK", [round(l, 3) for l in losses])
 
 
+def check_tp_kv_repeat():
+    """Fewer KV heads than shards (chatglm3's hkv=2 over 4): the TP engine
+    repeats each KV head so every shard attends to its own group's head,
+    and serves the 1-device engine's greedy tokens."""
+    import dataclasses
+
+    from repro.parallel import ParallelContext
+    from repro.parallel.tp import make_serving_mesh, make_tp_context
+    from repro.serve import PagedServeEngine, Request
+
+    cfg = dataclasses.replace(get_config("chatglm3-6b", smoke=True),
+                              num_heads=8, num_kv_heads=2, d_ff=128)
+    bundle = build_model(cfg)
+    params = bundle.init_params(jax.random.PRNGKey(0))
+
+    def serve(pctx):
+        eng = PagedServeEngine(bundle, params, pctx, slots=2, page_size=8,
+                               num_pages=16, prefill_chunk=8)
+        reqs = [Request(rid=i, prompt=[3 + i, 7, 9, 11, 2 + i] * 3,
+                        max_new_tokens=8) for i in range(3)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained()
+        return eng, [r.output for r in reqs]
+
+    _, one = serve(ParallelContext(None))
+    eng, tp = serve(make_tp_context(make_serving_mesh(4)))
+    assert eng.tp_plan.kv_repeat == 2, eng.tp_plan
+    assert tp == one, (tp, one)
+    # each device holds one KV head of every page: half the 2-head pool
+    assert eng.kv_pool_bytes_per_device() * 4 == eng.kv_pool_bytes()
+    print("CHECK tp_kv_repeat OK")
+
+
 CHECKS = {
     "moe_ep": check_moe_ep_matches_local,
     "pipeline_parallel": check_pipeline_parallel,
     "compression": check_compression,
     "elastic_remesh": check_elastic_remesh,
+    "tp_kv_repeat": check_tp_kv_repeat,
 }
 
 if __name__ == "__main__":

@@ -70,6 +70,25 @@ class TestProfiles:
         # explicit name outranks the env
         assert get_profile("tpu_v5e").name == "tpu_v5e"
 
+    @pytest.mark.parametrize("kind,expect", [("TPU v5 lite", "tpu_v5e"),
+                                             ("TPU v9 imaginary", None)])
+    def test_tpu_backend_resolves_device_kind(self, monkeypatch, kind,
+                                              expect):
+        import types
+
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(jax, "devices", lambda: [
+            types.SimpleNamespace(platform="tpu", device_kind=kind)])
+        # on a chip the device decides, whatever the environment says
+        monkeypatch.setenv("REPRO_HW_PROFILE", "cpu_interpret")
+        if expect is None:
+            with pytest.raises(KeyError, match="device kind"):
+                get_profile()
+        else:
+            assert get_profile().name == expect
+        assert get_profile("cpu_interpret").name == "cpu_interpret"
+
     def test_unknown_profile_raises(self):
         with pytest.raises(KeyError):
             get_profile("not_a_chip")

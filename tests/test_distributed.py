@@ -10,11 +10,6 @@ import pytest
 HERE = os.path.dirname(__file__)
 SCRIPT = os.path.join(HERE, "_dist_checks.py")
 
-# These all passed xfail-free since every shard_map region went fully
-# manual (explicit collectives on every mesh axis) — the partial-manual
-# regions that used to abort 0.4.x XLA's SPMD partitioner are gone; see
-# docs/known_failures.md for the history.
-
 
 def run_check(name: str, timeout: int = 420) -> str:
     env = dict(os.environ)
@@ -46,3 +41,7 @@ def test_crosspod_gradient_compression():
 @pytest.mark.slow
 def test_elastic_remesh_8_to_4_devices():
     run_check("elastic_remesh")
+
+
+def test_tp_serving_repeats_kv_heads_below_mesh():
+    run_check("tp_kv_repeat")

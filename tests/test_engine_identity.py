@@ -261,7 +261,7 @@ def test_identity_matrix_mesh4(engine, weights, kv_dtype,
     # the cells really ran sharded: 4-way plan, KV pool bytes per device
     # at least 3x below the logical pool (hkv=4 shards exactly 4x)
     assert eng.tp_plan is not None and eng.tp_plan.degree == 4
-    assert eng.tp_plan.shard_kv
+    assert eng.tp_plan.kv_repeat == 1
     assert eng.kv_pool_bytes_per_device() * 3 <= eng.kv_pool_bytes()
     assert eng.weight_bytes_per_device() * 2 <= _tree_bytes(eng.params)
 

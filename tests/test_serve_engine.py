@@ -194,3 +194,60 @@ def test_request_lifecycle_states(llama):
     while not req.done:
         eng.step()
     assert len(req.output) == 3 and eng.kv.used_pages == 0
+
+
+# ------------------------------------------------ logits vs plain forward
+#: max |served - forward| / max |forward| per family at smoke size.  The
+#: dense and rwkv6 steps are the forward's own bf16 operations in another
+#: shape (bitwise or near it on the CPU); the hybrid's engine recurrence and
+#: the forward's chunked SSD round differently at every bf16 mixer boundary
+#: and sit up to ~0.32 apart, as far as each sits from a float32 forward.
+_LOGITS_TOL = {"chatglm3-6b": 0.05, "rwkv6-3b": 0.05, "zamba2-1.2b": 0.4}
+
+
+@pytest.mark.parametrize("arch", sorted(_LOGITS_TOL))
+def test_served_logits_match_forward(arch):
+    import numpy as np
+
+    from repro.serve.logits_check import (forward_logits, logits_error,
+                                          served_logits)
+    cfg = get_config(arch, smoke=True)
+    bundle = build_model(cfg)
+    params = bundle.init_params(jax.random.PRNGKey(0))
+    eng = PagedServeEngine(bundle, params, PCTX, slots=2, page_size=8,
+                           prefill_chunk=8)
+    prompt = np.random.default_rng(0).integers(2, cfg.vocab_size,
+                                               37).tolist()
+    tokens, got = served_logits(eng, prompt, 6)
+    assert got.shape == (6, cfg.padded_vocab)
+    assert tokens == [int(i) for i in got.argmax(-1)]
+    ref = forward_logits(bundle, params, prompt, tokens)
+    _, rel = logits_error(got, ref, cfg.vocab_size)
+    assert rel <= _LOGITS_TOL[arch], rel
+    # a wrong position is caught: shift the reference by one token
+    _, off = logits_error(got[1:], ref[:-1], cfg.vocab_size)
+    assert off > _LOGITS_TOL[arch], off
+
+
+def test_launcher_serves_smoke_config(capsys, monkeypatch):
+    from repro.launch import compile_cache, serve
+    # keep this process's JAX config as the other tests expect it
+    monkeypatch.setattr(compile_cache, "use_compile_cache", lambda: "")
+    serve.main(["--arch", "chatglm3-6b", "--smoke", "--requests", "3",
+                "--max-new", "4"])
+    assert "served 3/3 requests" in capsys.readouterr().out
+
+
+def test_compile_cache_placed_from_the_environment(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CACHE_DIR)
+        assert CACHE_DIR.parent.joinpath("pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -1,5 +1,7 @@
 """Sharding-rule unit tests: logical axes -> PartitionSpec, ZeRO-1 specs,
 batch specs, cache specs — pure functions, no devices needed."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -40,6 +42,25 @@ def test_kv_heads_replicated_when_not_divisible():
     c = ctx()
     assert logical_to_spec(("embed", "kv_heads"), c, kv_heads=4) == P(None, None)
     assert logical_to_spec(("embed", "kv_heads"), c, kv_heads=32) == P(None, "model")
+
+
+@pytest.mark.parametrize("hkv,degree,repeat", [(4, 4, 1), (8, 4, 1),
+                                                (2, 4, 2), (1, 4, 4)])
+def test_tp_plan_repeats_kv_heads_below_the_mesh(hkv, degree, repeat):
+    from repro.parallel.tp import plan_tp
+    cfg = dataclasses.replace(get_config("chatglm3-6b", smoke=True),
+                              num_heads=8, num_kv_heads=hkv, d_ff=128)
+    plan = plan_tp(cfg, degree)
+    assert plan.kv_repeat == repeat
+    assert plan.local_cfg.num_kv_heads == hkv * repeat // degree
+
+
+def test_tp_plan_rejects_kv_heads_that_do_not_divide():
+    from repro.parallel.tp import plan_tp
+    cfg = dataclasses.replace(get_config("chatglm3-6b", smoke=True),
+                              num_heads=12, num_kv_heads=3, d_ff=128)
+    with pytest.raises(ValueError, match="KV heads"):
+        plan_tp(cfg, 4)
 
 
 def test_experts_on_data_axis():
