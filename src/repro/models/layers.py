@@ -387,34 +387,38 @@ def attention_decode_paged(
     ps = k_pages.shape[1]
     positions = lengths[:, None] + jnp.arange(t)[None]         # (B, T)
     q, k_new, v_new = project_qkv(p, prefix, cfg, x, positions, apply_rope)
-    write = jnp.arange(t)[None] < new_counts[:, None]          # (B, T)
-    page_idx = jnp.minimum(positions // ps, block_tables.shape[1] - 1)
-    bidx = jnp.arange(b)[:, None]
-    pids = jnp.where(write, block_tables[bidx, page_idx], 0)
-    offs = jnp.where(write, positions % ps, 0)
     quantized = k_scales is not None
-    if quantized:
-        kq = quantize_channelwise(k_new, axis=-1)   # per (token, head)
-        vq = quantize_channelwise(v_new, axis=-1)
-        k_pages = k_pages.at[pids, offs].set(kq.q)
-        v_pages = v_pages.at[pids, offs].set(vq.q)
-        k_scales = k_scales.at[pids, offs].set(kq.scale[..., 0])
-        v_scales = v_scales.at[pids, offs].set(vq.scale[..., 0])
-    else:
-        k_pages = k_pages.at[pids, offs].set(k_new.astype(k_pages.dtype))
-        v_pages = v_pages.at[pids, offs].set(v_new.astype(v_pages.dtype))
-    # logical contiguous view: (B, P_max * page_size, Hkv, Dh)
-    k_all = jnp.take(k_pages, block_tables, axis=0).reshape(
-        b, -1, *k_pages.shape[2:])
-    v_all = jnp.take(v_pages, block_tables, axis=0).reshape(
-        b, -1, *v_pages.shape[2:])
-    if quantized:  # dequantize the gathered view before the softmax
-        ks_all = jnp.take(k_scales, block_tables, axis=0).reshape(
-            b, -1, k_scales.shape[2])
-        vs_all = jnp.take(v_scales, block_tables, axis=0).reshape(
-            b, -1, v_scales.shape[2])
-        k_all = (k_all.astype(jnp.float32) * ks_all[..., None]).astype(x.dtype)
-        v_all = (v_all.astype(jnp.float32) * vs_all[..., None]).astype(x.dtype)
+    # the page writes and the block-table gathers, named for the trace
+    with jax.named_scope("kv_pages"):
+        write = jnp.arange(t)[None] < new_counts[:, None]      # (B, T)
+        page_idx = jnp.minimum(positions // ps, block_tables.shape[1] - 1)
+        bidx = jnp.arange(b)[:, None]
+        pids = jnp.where(write, block_tables[bidx, page_idx], 0)
+        offs = jnp.where(write, positions % ps, 0)
+        if quantized:
+            kq = quantize_channelwise(k_new, axis=-1)   # per (token, head)
+            vq = quantize_channelwise(v_new, axis=-1)
+            k_pages = k_pages.at[pids, offs].set(kq.q)
+            v_pages = v_pages.at[pids, offs].set(vq.q)
+            k_scales = k_scales.at[pids, offs].set(kq.scale[..., 0])
+            v_scales = v_scales.at[pids, offs].set(vq.scale[..., 0])
+        else:
+            k_pages = k_pages.at[pids, offs].set(k_new.astype(k_pages.dtype))
+            v_pages = v_pages.at[pids, offs].set(v_new.astype(v_pages.dtype))
+        # logical contiguous view: (B, P_max * page_size, Hkv, Dh)
+        k_all = jnp.take(k_pages, block_tables, axis=0).reshape(
+            b, -1, *k_pages.shape[2:])
+        v_all = jnp.take(v_pages, block_tables, axis=0).reshape(
+            b, -1, *v_pages.shape[2:])
+        if quantized:  # dequantize the gathered view before the softmax
+            ks_all = jnp.take(k_scales, block_tables, axis=0).reshape(
+                b, -1, k_scales.shape[2])
+            vs_all = jnp.take(v_scales, block_tables, axis=0).reshape(
+                b, -1, v_scales.shape[2])
+            k_all = (k_all.astype(jnp.float32)
+                     * ks_all[..., None]).astype(x.dtype)
+            v_all = (v_all.astype(jnp.float32)
+                     * vs_all[..., None]).astype(x.dtype)
     s = k_all.shape[1]
     # causal within the chunk: query i sees logical positions <= lengths + i
     mask = (jnp.arange(s)[None, None] <= positions[:, :, None])[:, None, None]

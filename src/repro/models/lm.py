@@ -296,20 +296,24 @@ def lm_decode_paged(
         for j in range(me):
             lp = {k[len(f"blk.{j}."):]: v for k, v in blk_p.items()
                   if k.startswith(f"blk.{j}.")}
-            h = rms_norm(x, lp["ln1"] + 1.0, cfg.norm_eps)
             scales = ({"k_scales": ks_blk[j], "v_scales": vs_blk[j]}
                       if quantized_kv else {})
-            attn_out, *upd = attention_decode_paged(
-                lp, "attn", cfg, h, kc_blk[j], vc_blk[j],
-                lengths, new_counts, block_tables, **scales
-            )
+            # named scopes mark the device trace's ops; code is unchanged
+            with jax.named_scope("attn"):
+                h = rms_norm(x, lp["ln1"] + 1.0, cfg.norm_eps)
+                attn_out, *upd = attention_decode_paged(
+                    lp, "attn", cfg, h, kc_blk[j], vc_blk[j],
+                    lengths, new_counts, block_tables, **scales
+                )
             new.append(upd)
             x = x + attn_out
-            h = rms_norm(x, lp["ln2"] + 1.0, cfg.norm_eps)
-            if cfg.num_experts and j == me - 1:
-                x = x + moe_block(lp, "moe", cfg, h, pctx)
-            else:
-                x = x + swiglu(h, lp["mlp.w_gate"], lp["mlp.w_up"], lp["mlp.w_down"], cfg)
+            with jax.named_scope("mlp"):
+                h = rms_norm(x, lp["ln2"] + 1.0, cfg.norm_eps)
+                if cfg.num_experts and j == me - 1:
+                    x = x + moe_block(lp, "moe", cfg, h, pctx)
+                else:
+                    x = x + swiglu(h, lp["mlp.w_gate"], lp["mlp.w_up"],
+                                   lp["mlp.w_down"], cfg)
         # transpose [per-layer][field] -> per-field stacks (k, v[, ks, vs])
         return x, tuple(jnp.stack([u[f] for u in new])
                         for f in range(len(new[0])))
@@ -327,8 +331,9 @@ def lm_decode_paged(
             x, y = scan_body(x, jax.tree.map(lambda a: a[i], xs))
             ys.append(y)
         upd = tuple(jnp.stack([y[f] for y in ys]) for f in range(len(ys[0])))
-    x = rms_norm(x, rest["final_norm"] + 1.0, cfg.norm_eps)
-    logits = _lm_head(params, rest, cfg, x)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, rest["final_norm"] + 1.0, cfg.norm_eps)
+        logits = _lm_head(params, rest, cfg, x)
     new_cache = {"k": upd[0], "v": upd[1]}
     if quantized_kv:
         new_cache["k_scale"], new_cache["v_scale"] = upd[2], upd[3]

@@ -49,6 +49,23 @@ from ..parallel.sharding import ParallelContext
 from .paged_cache import OutOfPages, PagedKVCache
 from .scheduler import (DECODING, DONE, PREFILLING, FifoScheduler, Request)
 from .state_cache import TRASH_STATE, StateCache
+from .tracing import SpanLog
+
+
+def named_programs(step):
+    """Jit ``step`` as three programs, ``serve_prefill``, ``serve_decode``
+    and ``serve_verify`` (HLO modules ``jit_serve_*``), so a device trace
+    says which entry point an operation belongs to.  Each compiles only the
+    shapes it is called with: prefill ``(1, chunk)``, the decode tick
+    ``(slots, 1)``, the speculative verify ``(slots, spec_k + 1)``."""
+    def program(name):
+        def fn(params, cache, tokens, lengths, new_counts, tables):
+            return step(params, cache, tokens, lengths, new_counts, tables)
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn)
+
+    return (program("serve_prefill"), program("serve_decode"),
+            program("serve_verify"))
 
 
 @dataclasses.dataclass
@@ -59,18 +76,27 @@ class EngineMetrics:
     prefill_tokens: int = 0
     decode_tokens: int = 0
     preemptions: int = 0
-    prefill_time_s: float = 0.0   # device time inside prefill-chunk calls
-    decode_time_s: float = 0.0    # device time inside decode-tick calls
+    prefill_calls: int = 0        # prefill-chunk model calls
+    decode_calls: int = 0         # decode-tick model calls (verify calls on
+                                  #   the speculative engine); rows are
+                                  #   ``decode_tokens`` on the plain engine
+    # host wall time around each model call, which ends in
+    # ``block_until_ready`` (the same clock reads as its span)
+    prefill_time_s: float = 0.0
+    decode_time_s: float = 0.0
     started_at: float = dataclasses.field(default_factory=time.perf_counter)
     ttfts: List[float] = dataclasses.field(default_factory=list)
-    util_samples: List[float] = dataclasses.field(default_factory=list)
+    # page utilization, one sample per tick, kept as running aggregates
+    util_max: float = 0.0
+    util_sum: float = 0.0
+    util_count: int = 0
     # speculative decoding (repro.spec); all zero on the plain engine
     spec_steps: int = 0           # verify steps, counted per participating
                                   #   request (a batched tick adds one per
                                   #   DECODING slot it verified)
     draft_proposed: int = 0       # draft tokens proposed across all steps
     draft_accepted: int = 0       # ... accepted by the target AND emitted
-    draft_time_s: float = 0.0     # time spent producing draft proposals
+    draft_time_s: float = 0.0     # host wall time producing draft proposals
     # prefix cache (prefix_sharing=True); hit/cow stay zero without it
     prefix_hit_tokens: int = 0    # prompt tokens whose KV came from the
                                   #   prefix cache instead of prefill
@@ -87,13 +113,13 @@ class EngineMetrics:
 
     @property
     def decode_tps(self) -> float:
-        """Decode tokens per second of *decode device time* (phase-local,
+        """Decode tokens per second of decode-call wall time (phase-local,
         so prefill pressure and host scheduling don't dilute it)."""
         return self.decode_tokens / max(self.decode_time_s, 1e-9)
 
     @property
     def prefill_tps(self) -> float:
-        """Prompt tokens per second of *prefill device time*."""
+        """Prompt tokens per second of prefill-call wall time."""
         return self.prefill_tokens / max(self.prefill_time_s, 1e-9)
 
     @property
@@ -131,13 +157,18 @@ class EngineMetrics:
         prompt KV per token actually materialized."""
         return self.prompt_pages_logical / max(self.prompt_pages_unique, 1)
 
+    def observe_utilization(self, u: float) -> None:
+        self.util_max = max(self.util_max, u)
+        self.util_sum += u
+        self.util_count += 1
+
     @property
     def peak_page_utilization(self) -> float:
-        return max(self.util_samples, default=0.0)
+        return self.util_max
 
     @property
     def mean_page_utilization(self) -> float:
-        return float(np.mean(self.util_samples)) if self.util_samples else 0.0
+        return self.util_sum / self.util_count if self.util_count else 0.0
 
     def summary(self) -> Dict[str, float]:
         out = {
@@ -344,6 +375,8 @@ class PagedServeEngine:
         self.active: List[Optional[Request]] = [None] * slots
         self.last_tokens = np.zeros((slots,), np.int64)
         self.metrics = EngineMetrics()
+        # program spans (repro.serve.tracing); off until spans.enable()
+        self.spans = SpanLog()
         copy_fn = lambda c, s, d: jax.tree.map(
             lambda a: a.at[:, :, d].set(a[:, :, s]), c)
         if self.tp_plan is not None:
@@ -358,8 +391,8 @@ class PagedServeEngine:
             cspecs = self._tp.tp_cache_specs(self.cache, pctx.tp_axis)
             self.params = self._tp.shard_tree(params, mesh, pspecs)
             self.cache = self._tp.shard_tree(self.cache, mesh, cspecs)
-            self._decode = jax.jit(self._tp.make_tp_decode_paged(
-                bundle, pctx, self.tp_plan, pspecs, cspecs))
+            step = self._tp.make_tp_decode_paged(
+                bundle, pctx, self.tp_plan, pspecs, cspecs)
             # pin the copy output to the pool sharding so COW/defrag moves
             # never silently gather a pool onto every device
             from jax.sharding import NamedSharding
@@ -368,8 +401,8 @@ class PagedServeEngine:
                 is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
             self._copy_page = jax.jit(copy_fn, out_shardings=cache_sh)
         else:
-            self._decode = jax.jit(
-                lambda p, c, t, l, n, bt: bundle.decode_paged(p, c, t, l, n, bt, pctx))
+            step = lambda p, c, t, l, n, bt: bundle.decode_paged(
+                p, c, t, l, n, bt, pctx)
             if self.state is not None:
                 # Key-aware device copies: a mixed cache holds KV page
                 # pools (page axis 2) AND state pools (slot axis 1), so
@@ -386,6 +419,8 @@ class PagedServeEngine:
                 # layers and pools at once.  src/dst are traced scalars:
                 # one compilation serves every copy.
                 self._copy_page = jax.jit(copy_fn)
+        # the speculative subclass's verify tick calls ``_verify``
+        self._prefill, self._decode_step, self._verify = named_programs(step)
         if use_graph:
             # Graph-compiled chunked prefill AND decode tick: each traced
             # once at the engine's fixed shapes — (B=1, T=chunk) and
@@ -409,11 +444,6 @@ class PagedServeEngine:
                     bundle, params, self.cache, slots=slots,
                     table_width=self._table_width(1), pctx=pctx,
                     impl=graph_impl, cost_model=graph_cost_model)
-        else:
-            # same jit fn for all three entry points; shapes differ
-            # (prefill: B=1 T=chunk; decode tick: B=slots T=1)
-            self._prefill = self._decode
-            self._decode_step = self._decode
 
     def _table_width(self, t: int) -> int:
         """Combined block-table width for a forward over T=``t`` positions:
@@ -516,12 +546,15 @@ class PagedServeEngine:
         batched decode for all DECODING slots.  Returns active requests.
         The whole tick runs under this engine's tuned-config scope, so a
         subclass tick phase (speculative verify) resolves through it too."""
-        with scoped_cache(self.tune_cache):
-            self._admit()
+        spans = self.spans
+        with spans.span("step", tick=self.metrics.ticks), \
+                scoped_cache(self.tune_cache):
+            with spans.span("admit") as sp:
+                sp.set(admitted=self._admit())
             self._prefill_tick()
             self._decode_tick()
         self.metrics.ticks += 1
-        self.metrics.util_samples.append(self.kv.utilization())
+        self.metrics.observe_utilization(self.kv.utilization())
         return sum(r is not None for r in self.active)
 
     def run_until_drained(self, max_ticks: int = 10_000) -> EngineMetrics:
@@ -535,14 +568,18 @@ class PagedServeEngine:
     def _active_requests(self) -> List[Request]:
         return [r for r in self.active if r is not None]
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Place waiting requests in free slots; returns how many."""
         # Gate on available pages (free + lazily-evictable prefix cache) so
         # a freshly-preempted request is not bounced straight back into the
         # pool that just evicted it.
         if self.kv.available_pages == 0:
-            return
+            return 0
         free = [i for i, r in enumerate(self.active) if r is None]
-        for slot, req in self.sched.admit(free):
+        placed = self.sched.admit(free)
+        for slot, req in placed:
+            if not req.admitted_at:   # kept across preemption
+                req.admitted_at = time.perf_counter()
             self.active[slot] = req
             toks = req.prefill_tokens()
             self.metrics.prompt_pages_logical += self.kv.pages_for(len(toks))
@@ -557,6 +594,7 @@ class PagedServeEngine:
             if self.state is not None:
                 self.state.alloc(slot)
             self._on_admit(slot, req)
+        return len(placed)
 
     def _on_admit(self, slot: int, req: Request) -> None:
         """Placement hook for subclasses (the speculative engine notifies
@@ -641,56 +679,69 @@ class PagedServeEngine:
         prefilling = [r for r in self._active_requests()
                       if r.state == PREFILLING]
         unique0 = self._net_unique_pages()
+        spans = self.spans
         for req, n in self.sched.prefill_plan(prefilling):
             if self.active[req.slot] is not req:
                 continue  # preempted earlier this tick by a sibling's alloc
             toks_all = req.prefill_tokens()
-            if not self._ensure_pages(req, req.prefill_pos + n):
-                continue
-            self._sync_page_copies()
-            chunk = toks_all[req.prefill_pos:req.prefill_pos + n]
-            padded = chunk + [0] * (self.prefill_chunk - n)
-            if self.state is not None:
-                # only the chunk's last real token needs its state kept —
-                # the forward carries state across tokens in registers, so
-                # intermediate (and padded) positions write to the sink
-                write_ids = np.full((1, self.prefill_chunk), TRASH_STATE,
-                                    np.int32)
-                write_ids[0, n - 1] = self.state.cur(req.slot)
-            else:
-                write_ids = None
-            t0 = time.perf_counter()
-            logits, self.cache = self._prefill(
-                self.params, self.cache,
-                jnp.asarray([padded], jnp.int32),
-                jnp.asarray([req.prefill_pos], jnp.int32),
-                jnp.asarray([n], jnp.int32),
-                jnp.asarray(self._tables([req.slot], write_ids)))
-            jax.block_until_ready(logits)
-            self.metrics.prefill_time_s += time.perf_counter() - t0
-            req.prefill_pos += n
-            self.kv.commit(req.slot, req.prefill_pos)
-            if self.state is not None:
-                self.state.commit(req.slot, req.prefill_pos)
-            if self.prefix_sharing:
-                # publish completed pages so siblings (and later waves)
-                # can share them; identical pages prefix-filled in parallel
-                # retro-dedup onto one physical copy here
-                self.kv.register_prefix(req.slot, toks_all)
-            self.metrics.prefill_tokens += n
-            if req.prefill_pos == len(toks_all):
-                # prompt (+ recompute suffix) fully cached: the last real
-                # row of this chunk's logits is the next-token distribution
-                nxt = int(jnp.argmax(logits[0, n - 1]))
-                if not req.first_token_at:
-                    req.first_token_at = time.perf_counter()
-                    self.metrics.ttfts.append(req.ttft)
-                req.output.append(nxt)
-                self.last_tokens[req.slot] = nxt
-                req.state = DECODING
-                self._maybe_finish(req, nxt)
+            with spans.span("pages"):
+                if not self._ensure_pages(req, req.prefill_pos + n):
+                    continue
+                self._sync_page_copies()
+                chunk = toks_all[req.prefill_pos:req.prefill_pos + n]
+                padded = chunk + [0] * (self.prefill_chunk - n)
+                if self.state is not None:
+                    # only the chunk's last real token needs its state kept
+                    # — the forward carries state across tokens in
+                    # registers, so intermediate (and padded) positions
+                    # write to the sink
+                    write_ids = np.full((1, self.prefill_chunk), TRASH_STATE,
+                                        np.int32)
+                    write_ids[0, n - 1] = self.state.cur(req.slot)
+                else:
+                    write_ids = None
+                tables = self._tables([req.slot], write_ids)
+            with spans.span("inputs"):
+                args = (jnp.asarray([padded], jnp.int32),
+                        jnp.asarray([req.prefill_pos], jnp.int32),
+                        jnp.asarray([n], jnp.int32), jnp.asarray(tables))
+            with spans.timed("prefill", rid=req.rid, new_tokens=n,
+                             cached_tokens=req.prefill_pos) as call:
+                logits, self.cache = self._prefill(self.params, self.cache,
+                                                   *args)
+                jax.block_until_ready(logits)
+            self.metrics.prefill_time_s += call.seconds
+            self.metrics.prefill_calls += 1
+            with spans.span("sample", phase="prefill"):
+                self._after_prefill(req, n, toks_all, logits)
         self.metrics.prompt_pages_unique += (self._net_unique_pages()
                                              - unique0)
+
+    def _after_prefill(self, req: Request, n: int, toks_all: List[int],
+                       logits) -> None:
+        """Commit a prefilled chunk; once the prompt is cached, take the
+        first token from the chunk's last real row."""
+        req.prefill_pos += n
+        self.kv.commit(req.slot, req.prefill_pos)
+        if self.state is not None:
+            self.state.commit(req.slot, req.prefill_pos)
+        if self.prefix_sharing:
+            # publish completed pages so siblings (and later waves) can
+            # share them; identical pages prefix-filled in parallel
+            # retro-dedup onto one physical copy here
+            self.kv.register_prefix(req.slot, toks_all)
+        self.metrics.prefill_tokens += n
+        if req.prefill_pos == len(toks_all):
+            # prompt (+ recompute suffix) fully cached: the last real row
+            # of this chunk's logits is the next-token distribution
+            nxt = int(jnp.argmax(logits[0, n - 1]))
+            if not req.first_token_at:
+                req.first_token_at = time.perf_counter()
+                self.metrics.ttfts.append(req.ttft)
+            req.output.append(nxt)
+            self.last_tokens[req.slot] = nxt
+            req.state = DECODING
+            self._maybe_finish(req, nxt)
 
     def _decode_tick(self) -> None:
         # oldest first, so page pressure evicts the youngest (LIFO) and the
@@ -698,41 +749,49 @@ class PagedServeEngine:
         decoding = sorted(
             (r for r in self._active_requests() if r.state == DECODING),
             key=lambda r: r.admit_seq)
-        for req in decoding:
-            self._ensure_pages(req, self.kv.length(req.slot) + 1)
-        self._sync_page_copies()
-        decoding = [r for r in self._active_requests() if r.state == DECODING]
-        if not decoding:
-            return
-        lengths = np.array([self.kv.length(s) for s in range(self.slots)],
-                           np.int32)
-        counts = np.zeros((self.slots,), np.int32)
-        for r in decoding:
-            counts[r.slot] = 1
-        if self.state is not None:
-            write_ids = np.full((self.slots, 1), TRASH_STATE, np.int32)
+        spans = self.spans
+        with spans.span("pages"):
+            for req in decoding:
+                self._ensure_pages(req, self.kv.length(req.slot) + 1)
+            self._sync_page_copies()
+            decoding = [r for r in self._active_requests()
+                        if r.state == DECODING]
+            if not decoding:
+                return
+            lengths = np.array([self.kv.length(s) for s in range(self.slots)],
+                               np.int32)
+            counts = np.zeros((self.slots,), np.int32)
             for r in decoding:
-                write_ids[r.slot, 0] = self.state.cur(r.slot)
-        else:
-            write_ids = None
-        t0 = time.perf_counter()
-        logits, self.cache = self._decode_step(
-            self.params, self.cache,
-            jnp.asarray(self.last_tokens[:, None], jnp.int32),
-            jnp.asarray(lengths), jnp.asarray(counts),
-            jnp.asarray(self._tables(range(self.slots), write_ids)))
-        jax.block_until_ready(logits)
-        self.metrics.decode_time_s += time.perf_counter() - t0
-        next_tokens = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
-        for req in decoding:
-            self.kv.commit(req.slot, self.kv.length(req.slot) + 1)
+                counts[r.slot] = 1
             if self.state is not None:
-                self.state.commit(req.slot, self.kv.length(req.slot))
-            tok = int(next_tokens[req.slot])
-            req.output.append(tok)
-            self.last_tokens[req.slot] = tok
-            self.metrics.decode_tokens += 1
-            self._maybe_finish(req, tok)
+                write_ids = np.full((self.slots, 1), TRASH_STATE, np.int32)
+                for r in decoding:
+                    write_ids[r.slot, 0] = self.state.cur(r.slot)
+            else:
+                write_ids = None
+            tables = self._tables(range(self.slots), write_ids)
+        with spans.span("inputs"):
+            args = (jnp.asarray(self.last_tokens[:, None], jnp.int32),
+                    jnp.asarray(lengths), jnp.asarray(counts),
+                    jnp.asarray(tables))
+        with spans.timed("decode", rows=len(decoding),
+                         cached_tokens=int(np.dot(lengths, counts))) as call:
+            logits, self.cache = self._decode_step(self.params, self.cache,
+                                                   *args)
+            jax.block_until_ready(logits)
+        self.metrics.decode_time_s += call.seconds
+        self.metrics.decode_calls += 1
+        with spans.span("sample", phase="decode"):
+            next_tokens = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            for req in decoding:
+                self.kv.commit(req.slot, self.kv.length(req.slot) + 1)
+                if self.state is not None:
+                    self.state.commit(req.slot, self.kv.length(req.slot))
+                tok = int(next_tokens[req.slot])
+                req.output.append(tok)
+                self.last_tokens[req.slot] = tok
+                self.metrics.decode_tokens += 1
+                self._maybe_finish(req, tok)
 
     def _maybe_finish(self, req: Request, tok: int) -> None:
         if (req.eos_id is not None and tok == req.eos_id) or \

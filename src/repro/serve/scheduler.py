@@ -80,6 +80,7 @@ class Request:
     admit_seq: int = -1           # admission order; youngest = max
     preemptions: int = 0
     submitted_at: float = 0.0
+    admitted_at: float = 0.0      # first admission (kept across preemption)
     first_token_at: float = 0.0
     finished_at: float = 0.0
 

@@ -42,6 +42,7 @@ import time
 from typing import Dict, List, Optional
 
 from .scheduler import SLO_THROUGHPUT, Request
+from .tracing import SpanLog
 
 _DONE = object()   # stream sentinel: the request finished
 
@@ -105,6 +106,8 @@ class AsyncServeFrontend:
 
     def __init__(self, engine):
         self.engine = engine
+        # the engine's span log (the slot engine has none: a disabled one)
+        self.spans = getattr(engine, "spans", None) or SpanLog()
         self._streams: List[TokenStream] = []
         self._rid = itertools.count()
         self._wake = asyncio.Event()
@@ -152,10 +155,12 @@ class AsyncServeFrontend:
         req = Request(rid=rid if rid is not None else next(self._rid),
                       prompt=list(prompt), max_new_tokens=max_new_tokens,
                       eos_id=eos_id, slo=slo)
-        req.submitted_at = time.perf_counter()
-        # safe between ticks: the driver only mutates engine state inside
-        # step(), and this coroutine never runs concurrently with it
-        self.engine.submit(req)
+        with self.spans.span("submit", rid=req.rid):
+            req.submitted_at = time.perf_counter()
+            # safe between ticks: ``_drive`` only mutates engine state
+            # inside step(), and this coroutine never runs concurrently
+            # with it
+            self.engine.submit(req)
         stream = TokenStream(req)
         self._streams.append(stream)
         self._wake.set()
@@ -178,17 +183,20 @@ class AsyncServeFrontend:
 
     def _pump(self) -> None:
         """Push tokens emitted since the last tick into their streams."""
-        live = []
-        for stream in self._streams:
-            req = stream.request
-            for tok in req.output[stream._delivered:]:
-                stream._queue.put_nowait(tok)
-            stream._delivered = len(req.output)
-            if req.done:
-                stream._queue.put_nowait(_DONE)
-            else:
-                live.append(stream)
-        self._streams = live
+        with self.spans.span("pump") as sp:
+            live, pushed = [], 0
+            for stream in self._streams:
+                req = stream.request
+                for tok in req.output[stream._delivered:]:
+                    stream._queue.put_nowait(tok)
+                pushed += len(req.output) - stream._delivered
+                stream._delivered = len(req.output)
+                if req.done:
+                    stream._queue.put_nowait(_DONE)
+                else:
+                    live.append(stream)
+            self._streams = live
+            sp.set(tokens=pushed)
 
     async def _drive(self) -> None:
         while self._running:

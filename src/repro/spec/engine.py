@@ -33,7 +33,6 @@ masked by length and rewritten in lockstep, see ``docs/quantization.md``).
 """
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Tuple
 
 import jax
@@ -81,7 +80,6 @@ class SpeculativeServeEngine(PagedServeEngine):
                                chunk=self.prefill_chunk,
                                kv_dtype=self.kv_dtype)
         self.draft: DraftProposer = draft if draft is not None else NgramDraft()
-        self._verify = self._decode  # same jit fn; shapes (slots, spec_k+1)
 
     def _decode_kernel_shapes(self):
         """Plain decode shapes plus the widened verify-batch GEMM (the
@@ -125,10 +123,10 @@ class SpeculativeServeEngine(PagedServeEngine):
         if not alive:
             return
 
-        t0 = time.perf_counter()
-        proposals = self.draft.propose(
-            [(r.slot, r, k) for r, k in alive])
-        self.metrics.draft_time_s += time.perf_counter() - t0
+        with self.spans.timed("draft", rows=len(alive)) as call:
+            proposals = self.draft.propose(
+                [(r.slot, r, k) for r, k in alive])
+        self.metrics.draft_time_s += call.seconds
 
         t_verify = self.spec_k + 1
         tokens = np.zeros((self.slots, t_verify), np.int32)
@@ -156,13 +154,15 @@ class SpeculativeServeEngine(PagedServeEngine):
                 for t in range(1 + len(p)):
                     write_ids[req.slot, t] = self.state.snapshot(
                         req.slot, int(lengths[req.slot]) + t + 1, copy=False)
-        t0 = time.perf_counter()
-        logits, self.cache = self._verify(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(lengths), jnp.asarray(counts),
-            jnp.asarray(self._tables(range(self.slots), write_ids)))
-        jax.block_until_ready(logits)
-        self.metrics.decode_time_s += time.perf_counter() - t0
+        with self.spans.timed("verify", rows=len(alive),
+                              new_tokens=int(counts.sum())) as call:
+            logits, self.cache = self._verify(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(lengths), jnp.asarray(counts),
+                jnp.asarray(self._tables(range(self.slots), write_ids)))
+            jax.block_until_ready(logits)
+        self.metrics.decode_time_s += call.seconds
+        self.metrics.decode_calls += 1
         greedy = np.asarray(jnp.argmax(logits, axis=-1))     # (slots, T)
 
         for req, _k in alive:
